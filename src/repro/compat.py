@@ -1,11 +1,6 @@
-"""Version compatibility shims for the pinned JAX toolchain.
+"""``shard_map`` with ``axis_names`` as any iterable of *manual* mesh axes.
 
-The repo targets the ``jax.shard_map`` API (top-level export, ``check_vma``
-keyword, ``axis_names`` for partial-manual meshes).  The baked-in container
-toolchain ships jax 0.4.37, where the same functionality lives at
-``jax.experimental.shard_map.shard_map`` with the older ``check_rep`` /
-``auto`` spelling.  ``shard_map`` below presents the new surface on either
-version so engine code is written once against the modern API.
+Forwards to ``jax.shard_map`` (all axes manual if ``axis_names`` is None).
 """
 from __future__ import annotations
 
@@ -13,39 +8,14 @@ from typing import Any
 
 import jax
 
-__all__ = ["shard_map", "axis_size"]
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` (static int), also on jax 0.4.x.
-
-    On 0.4.x a ``psum`` of the literal 1 constant-folds to the mesh axis
-    size as a plain Python int, which is what callers need for static
-    loop bounds and permutation tables.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+__all__ = ["shard_map"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
               axis_names: Any = None):
-    """``jax.shard_map`` facade that also runs on jax 0.4.x.
-
-    ``axis_names`` is the set of *manual* mesh axes (all axes if None), as
-    in the modern API; on 0.4.x it is translated to the complementary
-    ``auto`` frozenset.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset()
+    """``jax.shard_map``; ``axis_names`` is the set of manual mesh axes."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)

@@ -24,7 +24,8 @@ Model and estimator
 * Regression basis: plain polynomial or Laguerre in the moneyness
   ``b / K1``, pluggable ``degree``; the continuation value is fit by
   masked ridge-regularised normal equations over in-the-money paths
-  only (the classic Longstaff–Schwartz restriction).
+  only (the classic Longstaff–Schwartz restriction), solved by an
+  unrolled Cholesky factorisation.
 * Backward induction runs over a static Bermudan ``exercise_steps``
   schedule (a subset of lattice steps, terminal step mandatory; step 0
   is handled deterministically as ``max(intrinsic(s0), MC estimate)``).
@@ -56,6 +57,15 @@ LSMC_BASES = ("poly", "laguerre")
 # ridge added to the (moneyness-normalised) Gram matrix so an all-OTM
 # date — a singular regression — degrades to beta = 0 instead of NaN
 _RIDGE = 1e-10
+
+# a path is in the money when its payoff clears this fraction of the
+# moneyness scale.  Inside the fused program on a TPU (float64 as a
+# float32 pair) out-of-the-money payoffs came out as tiny nonzero values
+# in ~4% of (path, date) entries, and a bare `h > 0` counted those paths
+# in the money.  1e-6 of the strike cleared every such residue on a v5e
+# (the in-the-money counts then equal the CPU's at every date) and is
+# far below any payoff that moves a regression.
+_ITM_TOL = 1e-6
 
 
 def exercise_schedule(n_steps: int,
@@ -155,6 +165,31 @@ def simulate_basket(s0, sigma, rate, maturity, key, *, n_steps: int,
     return b, t
 
 
+def _spd_solve(a, b):
+    """Solve ``a @ x = b`` for a small symmetric positive-definite ``a``.
+
+    An unrolled Cholesky factorisation in plain jnp, so it compiles in
+    any dtype on every backend (the TPU's LU decomposition takes float32
+    only).  The ridge keeps every pivot positive.
+    """
+    n = a.shape[0]
+    low = [[None] * n for _ in range(n)]
+    for j in range(n):
+        low[j][j] = jnp.sqrt(a[j, j] - sum(low[j][k] ** 2 for k in range(j)))
+        for i in range(j + 1, n):
+            low[i][j] = (a[i, j] - sum(low[i][k] * low[j][k]
+                                       for k in range(j))) / low[j][j]
+    y = []
+    for i in range(n):
+        y.append((b[i] - sum(low[i][k] * y[k] for k in range(i)))
+                 / low[i][i])
+    x = [None] * n
+    for i in reversed(range(n)):
+        x[i] = (y[i] - sum(low[k][i] * x[k] for k in range(i + 1, n))
+                ) / low[i][i]
+    return jnp.stack(x)
+
+
 def _payoff_pos(b, alpha, zeta, w1, w2, k1, k2):
     """Intrinsic value of the 4-parameter payoff family, floored at 0
     (identical to the lattice engines' convention)."""
@@ -187,11 +222,15 @@ def _lsmc_row(s0, sigma, rate, maturity, k, alpha, zeta, w1, w2, k1, k2,
             bj, hj, dfj = x
             val = val * dfj
             phi = basis_matrix(bj / scale, degree, basis)    # (P, q)
-            itm = hj > 0.0
+            itm = hj > _ITM_TOL * scale
             a = phi * itm[:, None]
-            gram = a.T @ a / P + _RIDGE * jnp.eye(degree + 1)
-            beta = jnp.linalg.solve(gram, a.T @ (val * itm) / P)
-            cont = phi @ beta
+            # products and sums, not dots: the TPU runs a float64 dot as
+            # float32 MXU passes at bfloat16 precision
+            gram = (jnp.sum(a[:, :, None] * a[:, None, :], axis=0) / P
+                    + _RIDGE * jnp.eye(degree + 1))
+            beta = _spd_solve(gram, jnp.sum(a * (val * itm)[:, None],
+                                            axis=0) / P)
+            cont = jnp.sum(phi * beta, axis=1)
             return jnp.where(itm & (hj > cont), hj, val), None
 
         v, _ = jax.lax.scan(body, v, xs)

@@ -37,14 +37,25 @@ def intrinsic_grid(model: LatticeModel, payoff: PayoffProcess, level: int) -> np
 
 
 def price_notc_np(model: LatticeModel, payoff: PayoffProcess) -> float:
-    """Numpy oracle — O(N^2), vectorised per level."""
+    """Numpy oracle — O(N^2), vectorised per level.
+
+    Node ``i`` of level ``lvl`` sits at ``s0 * exp(m * sigma * sqrt(dt))``
+    with ``m = 2i - lvl`` in ``[-N, N]``, so the payoff is evaluated once
+    on those 2N+1 prices and each level reads every other entry (the
+    same values :func:`intrinsic_grid` gives, without a payoff call per
+    level).
+    """
     n = model.n_steps
     r = model.r
     p = model.p_star
-    v = intrinsic_grid(model, payoff, n)
+    m = np.arange(-n, n + 1, dtype=np.float64)
+    s = model.s0 * np.exp(m * model.sigma
+                          * math.sqrt(model.maturity / model.n_steps))
+    pay = np.maximum(payoff.intrinsic(s), 0.0)
+    v = pay[0::2]
     for lvl in range(n - 1, -1, -1):
         cont = (p * v[1:lvl + 2] + (1.0 - p) * v[:lvl + 1]) / r
-        v = np.maximum(intrinsic_grid(model, payoff, lvl), cont)
+        v = np.maximum(pay[n - lvl:n + lvl + 1:2], cont)
     return float(v[0])
 
 

@@ -9,7 +9,7 @@ historical hard-coded ``interpret=True``.  This module owns that policy:
     idiom);
   * whether Pallas kernels run compiled or in interpret mode there
     (:func:`resolve_interpret` / :func:`supports_compiled_pallas` — CPU
-    has **no** compiled Pallas lowering on the pinned jax 0.4.37:
+    has **no** compiled Pallas lowering in jax 0.9:
     ``pallas_call(interpret=False)`` raises ``ValueError: Only interpret
     mode is supported on CPU backend.``, so CPU policy is interpret);
   * the compiled-path dtype policy (:func:`default_dtype` — float64 on
@@ -17,7 +17,9 @@ historical hard-coded ``interpret=True``.  This module owns that policy:
     compiled lowerings carry no f64);
   * the XLA flags a platform wants (:func:`xla_flags` /
     :func:`apply_xla_flags` — the GPU set is the latency-hiding
-    scheduler / async-collectives exemplar named by the ROADMAP).
+    scheduler / async-collectives exemplar named by the ROADMAP);
+  * where the persistent compilation cache lives
+    (:func:`use_compile_cache`, called by the entry points only).
 
 What each kernel *promises* to a compiled lowering (no sorts, int32
 bookkeeping, declared dynamic gathers) is the per-kernel contract
@@ -29,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +40,8 @@ __all__ = [
     "PLATFORMS", "PlatformPolicy", "POLICIES", "detect_platform",
     "active_platform", "set_platform", "resolve_interpret",
     "supports_compiled_pallas", "default_dtype", "xla_flags",
-    "apply_xla_flags", "platform_summary",
+    "apply_xla_flags", "platform_summary", "use_compile_cache",
+    "tc_max_steps",
 ]
 
 PLATFORMS = ("cpu", "gpu", "tpu")
@@ -63,6 +67,9 @@ class PlatformPolicy:
     compiled_pallas: bool      # does pallas_call(interpret=False) lower?
     default_dtype: str         # "float64" | "float32" dtype policy
     xla_flags: tuple[str, ...] = ()
+    # deepest TC lattice the float64 PWL algebra prices to the oracle
+    # tolerance here (None: no limit); see tc_max_steps()
+    tc_max_steps: int | None = None
 
 
 POLICIES: dict[str, PlatformPolicy] = {
@@ -71,7 +78,7 @@ POLICIES: dict[str, PlatformPolicy] = {
     "gpu": PlatformPolicy("gpu", interpret=False, compiled_pallas=True,
                           default_dtype="float32", xla_flags=_GPU_XLA_FLAGS),
     "tpu": PlatformPolicy("tpu", interpret=False, compiled_pallas=True,
-                          default_dtype="float32"),
+                          default_dtype="float32", tc_max_steps=16),
 }
 
 # Explicit override installed by set_platform(); None = detect from jax.
@@ -87,9 +94,15 @@ def _validate(platform: str) -> str:
 
 
 def detect_platform() -> str:
-    """Platform jax is actually executing on (``jax.default_backend()``)."""
+    """Platform jax is actually executing on (``jax.default_backend()``).
+
+    A backend with no policy here is an error, never a quiet ``"cpu"``.
+    """
     backend = jax.default_backend()
-    return backend if backend in PLATFORMS else "cpu"
+    if backend not in PLATFORMS:
+        raise RuntimeError(f"jax backend {backend!r} has no platform "
+                           f"policy; expected one of {PLATFORMS}")
+    return backend
 
 
 def active_platform() -> str:
@@ -141,6 +154,20 @@ def default_dtype(platform: str | None = None):
     return jnp.dtype(POLICIES[key].default_dtype)
 
 
+def tc_max_steps(platform: str | None = None) -> int | None:
+    """Deepest TC lattice (``n_steps``) the platform's float64 prices.
+
+    The PWL algebra tells a kink from rounding noise with a relative
+    slope tolerance of 1e-9 (``core/pwl.py::_REL``), which needs IEEE
+    binary64.  XLA on a TPU keeps float64 as a pair of float32 words
+    (about 48 bits, float32's exponent range): there the noise passes
+    for kinks as the tree deepens, the knot count grows past the
+    capacity and prices drift.  None means no limit.
+    """
+    key = _validate(platform) if platform is not None else active_platform()
+    return POLICIES[key].tc_max_steps
+
+
 def xla_flags(platform: str | None = None) -> tuple[str, ...]:
     key = _validate(platform) if platform is not None else active_platform()
     return POLICIES[key].xla_flags
@@ -174,3 +201,23 @@ def platform_summary() -> dict:
         "xla_flags": list(pol.xla_flags),
         "jax_version": jax.__version__,
     }
+
+
+# <repo>/.jax_cache: a fixed path (the cache key includes it), git-ignored
+_REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    wins: nothing else is set.  Otherwise the cache goes to the fixed
+    ``<repo>/.jax_cache``.  Entry points (``chip_smoke.py``,
+    ``launch/price.py``, ``launch/serve_pricing.py``) call this before
+    their first compile; library imports and the test suite do not.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(_REPO_CACHE_DIR))
+    return str(_REPO_CACHE_DIR)

@@ -33,13 +33,37 @@ import jax.numpy as jnp
 from . import pwl as P
 from .lattice import LatticeModel
 from .payoff import PayoffProcess
-from .platform import resolve_interpret
+from .platform import active_platform, resolve_interpret, tc_max_steps
 
 __all__ = ["price_rz", "price_rz_batch", "rz_backward", "rz_level_step",
            "rz_level_step_lanes", "rz_backward_pallas", "RZResult",
-           "RZ_BACKENDS"]
+           "RZ_BACKENDS", "require_tc_depth"]
 
 RZ_BACKENDS = ("jnp", "pallas")
+
+# why backend="pallas" runs in interpret mode only (docs/KNOWN_ISSUES.md)
+RZ_COMPILED_REFUSAL = (
+    "the TC Pallas round (kernels/rz_step.py::rz_round) has no compiled "
+    "Mosaic lowering yet: under the x64 switch its lowering recurses "
+    "without end in convert_element_type (core/pwl.py::_searchsorted); "
+    "with x64 off Mosaic refuses the merge-path gathers of "
+    "core/pwl.py::_merge_take ('Only 2D gather is supported'); and its "
+    "per-block pieces output BlockSpec((1,)) breaks the rank-1 block "
+    "rule. Use backend='jnp' on an accelerator, or interpret=True.")
+
+
+def require_tc_depth(n_steps: int) -> None:
+    """Refuse a TC tree deeper than the platform's float64 prices
+    (``core/platform.py::tc_max_steps``), before anything is traced."""
+    limit = tc_max_steps()
+    if limit is not None and n_steps > limit:
+        raise NotImplementedError(
+            f"the TC engine prices at most n_steps={limit} on "
+            f"{active_platform()!r}, not {n_steps}: float64 there is a "
+            "pair of float32 words (about 48 bits), too coarse for the PWL "
+            "algebra's 1e-9 slope tolerance in deeper trees (on a TPU v5e "
+            "the N=64 smoke grid needed 55 knots where the CPU needs 23). "
+            "Price deeper TC trees on a CPU host.")
 
 
 @dataclasses.dataclass
@@ -225,9 +249,15 @@ def rz_backward_pallas(s0, sigma, rate, maturity, k, *, n_steps: int,
     of None runs one re-balanced block per round (no halo — the right
     choice whenever a whole level fits in VMEM); an explicit ``block``
     exercises the multi-block right-neighbour-halo scheme.
+
+    The kernel runs in interpret mode only: compiled (``interpret``
+    resolving to False, as on a TPU) it raises ``NotImplementedError``
+    rather than fall back to interpret mode or the jnp engine.
     """
     from .partition import kernel_round_plan
     from ..kernels.rz_step import rz_round
+    if not resolve_interpret(interpret):
+        raise NotImplementedError(RZ_COMPILED_REFUSAL)
     if payoff.params is None:
         raise ValueError(
             f"backend='pallas' needs a 4-parameter-family payoff "
@@ -298,6 +328,7 @@ def price_rz(model: LatticeModel, payoff: PayoffProcess,
     later ``set_platform`` never serves a stale compiled mode.
     """
     interpret = resolve_interpret(interpret)
+    require_tc_depth(model.n_steps)
     ask, bid, pieces = _price_rz_jit(
         jnp.float64(model.s0), jnp.float64(model.sigma), jnp.float64(model.rate),
         jnp.float64(model.maturity), jnp.float64(model.cost_rate),
@@ -318,6 +349,7 @@ def price_rz_batch(s0, sigma, rate, maturity, k, *, n_steps: int,
 
     Returns (ask, bid, max_pieces) arrays — the serving-engine workhorse.
     """
+    require_tc_depth(n_steps)
     s0, sigma, rate, maturity, k = jnp.broadcast_arrays(
         *(jnp.atleast_1d(jnp.asarray(v, jnp.float64))
           for v in (s0, sigma, rate, maturity, k)))

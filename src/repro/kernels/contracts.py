@@ -34,6 +34,7 @@ import dataclasses
 from typing import Callable, Dict, Tuple
 
 import jax
+import jax.extend.core as jcore
 import jax.numpy as jnp
 
 __all__ = [
@@ -184,7 +185,7 @@ def jaxpr_summary(jaxpr) -> Tuple[set, set]:
 
 
 def _walk(jaxpr, prims: set, dtypes: set) -> None:
-    is_leaf = lambda x: isinstance(x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))
+    is_leaf = lambda x: isinstance(x, (jcore.Jaxpr, jcore.ClosedJaxpr))
     for eqn in jaxpr.eqns:
         prims.add(eqn.primitive.name)
         for var in eqn.outvars:
@@ -193,9 +194,9 @@ def _walk(jaxpr, prims: set, dtypes: set) -> None:
                 dtypes.add(str(aval.dtype))
         for val in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(val, is_leaf=is_leaf):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jcore.ClosedJaxpr):
                     _walk(sub.jaxpr, prims, dtypes)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jcore.Jaxpr):
                     _walk(sub, prims, dtypes)
 
 

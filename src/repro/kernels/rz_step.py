@@ -30,22 +30,20 @@ the caller raises ``OverflowError`` if it exceeded K.  Halo lanes are
 excluded — their values go stale within a round, and their owning block
 reports the authoritative count.
 
-The PWL level step is now **sort-free** (``core/pwl.py``'s merge-path
+The PWL level step is **sort-free** (``core/pwl.py``'s merge-path
 envelope algebra: binary-search rank computation + gathers — no
 ``sort``/``argsort`` primitives, jaxpr-asserted by
-``tests/test_pwl_merge.py``), which removes the original blocker this
-kernel family was quarantined to interpret mode for.  What remains
-between it and a compiled Mosaic lowering is narrower and mechanical:
-the per-lane dynamic gathers of the binary searches and the int32
-knot-count bookkeeping — both now *declared* in the kernel's lowering
-contract (``kernels/contracts.py``) and statically asserted against the
-traced jaxpr by ``tests/test_lowering_contract.py``.  The execution
-mode is platform policy (``core/platform.py``): ``interpret=None``
-resolves to interpret on CPU (no compiled Pallas lowering there —
-CPU-exact float64, used by the parity tests and benchmarks) and to a
-real compiled lowering on GPU/TPU.  The BlockSpec / grid structure is
-unchanged — it was designed to be kept once the sorts disappeared, and
-they now have.
+``tests/test_pwl_merge.py``), and its per-lane dynamic gathers and
+int32 knot-count bookkeeping are *declared* in the kernel's lowering
+contract (``kernels/contracts.py``).  It still has no compiled Mosaic
+lowering: compiling for a v5e recurses without end in
+``convert_element_type`` under x64, refuses the merge-path gathers
+("Only 2D gather is supported") with x64 off, and rejects the rank-1
+``pieces`` block.  So the kernel runs in interpret mode only, and the
+engine (``core/rz.py::rz_backward_pallas``) raises
+``NotImplementedError`` when ``interpret`` resolves to False
+(``docs/KNOWN_ISSUES.md``); ``tests/test_tpu_compile.py`` keeps the
+2-D gather as a strict xfail.
 """
 from __future__ import annotations
 
@@ -54,6 +52,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import pwl as P
 from ..core.payoff import param_payoff
@@ -180,7 +179,7 @@ def rz_round(z: P.PWL, scalars, *, levels: int, block: int,
         pl.BlockSpec((S, block), lambda i: (0, nxt(i))),
         pl.BlockSpec((S, block), lambda i: (0, nxt(i))),
     ]
-    in_specs = [pl.BlockSpec(memory_space=pl.ANY)] + cur_specs
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + cur_specs
     operands = [scalars, *z]
     if halo:
         in_specs += nxt_specs

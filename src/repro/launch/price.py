@@ -170,6 +170,8 @@ def main():
                     help="lsmc regression basis degree")
     args = ap.parse_args()
     args.interpret = {"auto": None, "on": True, "off": False}[args.interpret]
+    from ..core.platform import use_compile_cache
+    use_compile_cache()
     if args.platform is not None:
         from ..core.platform import set_platform
         set_platform(args.platform)

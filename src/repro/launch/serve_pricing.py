@@ -173,6 +173,8 @@ def main() -> None:
                          "(with --gateway; restarted after 1s; with "
                          "--pool=process the crash is a real SIGKILL)")
     args = ap.parse_args()
+    from ..core.platform import use_compile_cache
+    use_compile_cache()
 
     depths = tuple(int(x) for x in args.n_steps.split(","))
     trace = synth_trace(args.requests, n_steps=depths,

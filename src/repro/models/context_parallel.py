@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PS
 
-from ..compat import axis_size, shard_map
+from ..compat import shard_map
 
 __all__ = ["ring_attention_local", "make_ring_attention"]
 
@@ -41,7 +41,7 @@ def ring_attention_local(q, k, v, axis_name: str, *, causal: bool = True,
     q: (B, Sl, KVH, G, hd); k, v: (B, Sl, KVH, hd).  Returns (B, Sl, KVH,
     G, hd) — exact global attention over the ring.
     """
-    W = axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     B, Sl, KVH, G, hd = q.shape
     scale = 1.0 / math.sqrt(hd)

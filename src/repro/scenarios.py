@@ -58,8 +58,9 @@ import numpy as np
 from .core.partition import (ShardPlan, plan_shards, scenario_costs,
                              shard_layout)
 from .core.payoff import param_payoff
-from .core.platform import resolve_interpret
-from .core.rz import RZ_BACKENDS, rz_backward, rz_backward_pallas
+from .core.platform import default_dtype, resolve_interpret
+from .core.rz import (RZ_BACKENDS, require_tc_depth, rz_backward,
+                      rz_backward_pallas)
 
 __all__ = ["ScenarioGrid", "GridResult", "ShardExecInfo",
            "price_grid_rz", "price_grid_notc", "price_grid_lsmc",
@@ -518,7 +519,9 @@ def price_grid_rz(grid: ScenarioGrid, *, capacity: int = 48,
     One jitted, vmapped call over the whole (bumped, if ``greeks``) batch;
     returns ask/bid surfaces of ``grid.shape``.  Raises ``OverflowError``
     if any scenario needs more than ``capacity`` PWL knots (re-run with a
-    larger capacity), mirroring :func:`repro.core.rz.price_rz`.
+    larger capacity), mirroring :func:`repro.core.rz.price_rz`, and
+    ``NotImplementedError`` for a tree deeper than the platform's
+    float64 prices (``core/platform.py::tc_max_steps``).
 
     ``backend="jnp"`` walks levels with ``lax.fori_loop`` over the full
     node axis; ``backend="pallas"`` runs the blocked VMEM rounds of
@@ -537,6 +540,7 @@ def price_grid_rz(grid: ScenarioGrid, *, capacity: int = 48,
     """
     interpret = resolve_interpret(interpret)
     _require_lattice(grid, "rz")
+    require_tc_depth(grid.n_steps)
     inputs, copies = _with_bumps(_grid_inputs(grid), greeks)
     if backend == "jnp":
         rows_fn, jit_fn = _rz_rows, _rz_grid_jit
@@ -642,9 +646,13 @@ _notc_grid_jnp = partial(jax.jit, static_argnames=("n_steps",))(
 
 def _notc_rows_pallas(s0, sigma, rate, maturity, alpha, zeta, w1, w2, k1, k2,
                       *, n_steps: int, levels: int, block: int,
-                      interpret: bool):
+                      interpret: bool, dtype: str):
+    """Rows through the lattice kernel at ``dtype`` (the platform policy:
+    float64 in interpret mode on the CPU, float32 where Mosaic compiles
+    it).  The per-row lattice constants and leaf payoffs are formed at
+    the input precision and rounded once at the kernel boundary; the
+    prices come back as float64."""
     from .kernels.binomial_step import lattice_round_param
-    dtype = jnp.float64
 
     def one(s0_, sig_, r_, t_, al_, ze_, w1_, w2_, k1_, k2_):
         dt = t_ / n_steps
@@ -653,28 +661,28 @@ def _notc_rows_pallas(s0, sigma, rate, maturity, alpha, zeta, w1, w2, k1, k2,
         p_up = (r - 1.0 / u) / (u - 1.0 / u)
         sig = sig_ * jnp.sqrt(dt)
         P = -(-(n_steps + 1) // block) * block
-        idx = jnp.arange(P, dtype=dtype)
+        idx = jnp.arange(P, dtype=s0_.dtype)
         s_leaf = s0_ * jnp.exp((2.0 * idx - n_steps) * sig)
         pay = (al_ * k1_ + w1_ * jnp.maximum(s_leaf - k1_, 0.0)
                + w2_ * jnp.maximum(s_leaf - k2_, 0.0) + ze_ * s_leaf)
-        v0 = jnp.maximum(pay, 0.0)
+        v0 = jnp.maximum(pay, 0.0).astype(dtype)
+        consts = [p_up, 1.0 / r, s0_, sig, al_, ze_, w1_, w2_, k1_, k2_]
         rounds = -(-n_steps // levels)
 
         def body(rr, v):
-            lvl0 = jnp.asarray(n_steps - rr * levels, dtype)
-            scalars = jnp.stack([lvl0, p_up, 1.0 / r, s0_, sig,
-                                 al_, ze_, w1_, w2_, k1_, k2_])
+            lvl0 = jnp.asarray(n_steps - rr * levels, s0_.dtype)
+            scalars = jnp.stack([lvl0, *consts]).astype(dtype)
             return lattice_round_param(v, scalars, levels=levels,
                                        block=block, interpret=interpret)
 
         return jax.lax.fori_loop(0, rounds, body, v0)[0]
 
     return jax.vmap(one)(s0, sigma, rate, maturity,
-                         alpha, zeta, w1, w2, k1, k2)
+                         alpha, zeta, w1, w2, k1, k2).astype(jnp.float64)
 
 
 _notc_grid_pallas = partial(jax.jit, static_argnames=(
-    "n_steps", "levels", "block", "interpret"))(_notc_rows_pallas)
+    "n_steps", "levels", "block", "interpret", "dtype"))(_notc_rows_pallas)
 
 
 def price_grid_notc(grid: ScenarioGrid, *, backend: str = "jnp",
@@ -706,7 +714,7 @@ def price_grid_notc(grid: ScenarioGrid, *, backend: str = "jnp",
     elif backend == "pallas":
         rows_fn, jit_fn = _notc_rows_pallas, _notc_grid_pallas
         static = dict(n_steps=grid.n_steps, levels=levels, block=block,
-                      interpret=interpret)
+                      interpret=interpret, dtype=default_dtype().name)
     else:
         raise ValueError(f"unknown backend {backend!r}; use 'jnp' or 'pallas'")
     mesh, plan = _resolve_shard(grid, args[0].shape[0], copies,

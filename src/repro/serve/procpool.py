@@ -38,6 +38,11 @@ Fault semantics match the gateway's thread-pool contract exactly:
 * a **request** error (e.g. a PWL capacity ``OverflowError``) is
   re-raised under its own type — the worker stays alive and healthy.
 
+Process replicas run on the CPU only.  The gateway parent has already
+imported JAX, and on an accelerator host it holds the chip, so a worker
+could not reach it: off the CPU, starting a :class:`ProcessReplica`
+raises (the thread pool is the served path there).
+
 :class:`ReplicaPool` is the factory the gateway consumes via
 ``pool={"thread","process"}``: ``factory(i)`` builds replica ``i`` and is
 also what ``restart_s`` respawn calls, so a killed process is replaced by
@@ -54,6 +59,7 @@ import threading
 import time
 from typing import Dict, Optional
 
+from ..core.platform import active_platform
 from .core import ChunkResult, ChunkSpec, _Pending
 from .replica import LocalReplica, ReplicaCrash
 
@@ -141,6 +147,16 @@ def _worker_main(conn, cfg: dict) -> None:
         conn.send(("err", "ValueError", f"unknown op {op!r}"))
 
 
+def _require_cpu_host() -> None:
+    """Refuse process replicas where the parent holds an accelerator."""
+    platform = active_platform()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"process replicas need the CPU platform, not {platform!r}: "
+            "this process already holds the accelerator, so a spawned "
+            "worker cannot reach it; use pool='thread'")
+
+
 class ProcessReplica:
     """A replica that prices chunks in its own spawned process.
 
@@ -190,6 +206,7 @@ class ProcessReplica:
     # lifecycle
     # ------------------------------------------------------------------ #
     def start(self) -> None:
+        _require_cpu_host()
         ctx = multiprocessing.get_context("spawn")
         parent, child = ctx.Pipe()
         self._proc = ctx.Process(target=_worker_main, args=(child, self._cfg),

@@ -10,7 +10,7 @@ lowers the kernel for real.
 Dynamic half: where the platform has a compiled Pallas lowering
 (``supports_compiled_pallas()``), every kernel also runs
 ``interpret=False`` and must match the interpret oracle within its
-declared per-dtype tolerance.  On CPU (jax 0.4.37:
+declared per-dtype tolerance.  On CPU (jax 0.9:
 ``ValueError: Only interpret mode is supported on CPU backend.``) those
 runs skip with that reason — the CPU CI lane covers the static
 contracts and the interpret oracles; GPU/TPU lanes light up the real

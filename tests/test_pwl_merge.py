@@ -21,6 +21,7 @@ import pytest
 
 from repro.core import pwl as P
 from repro.core import pwl_ref as R
+from repro.kernels.contracts import jaxpr_summary
 
 
 @contextlib.contextmanager
@@ -152,21 +153,8 @@ def test_cone_merge_path_equals_sort_based(rng):
 # --------------------------------------------------------------------- #
 # jaxpr: the traced TC hot path must be sort-free
 # --------------------------------------------------------------------- #
-def _primitives(jaxpr, acc):
-    is_leaf = lambda x: isinstance(x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))
-    for eqn in jaxpr.eqns:
-        acc.add(eqn.primitive.name)
-        for v in eqn.params.values():
-            for sub in jax.tree_util.tree_leaves(v, is_leaf=is_leaf):
-                if isinstance(sub, jax.core.ClosedJaxpr):
-                    _primitives(sub.jaxpr, acc)
-                elif isinstance(sub, jax.core.Jaxpr):
-                    _primitives(sub, acc)
-    return acc
-
-
 def _assert_sort_free(fn, *args):
-    names = _primitives(jax.make_jaxpr(fn)(*args).jaxpr, set())
+    names, _ = jaxpr_summary(jax.make_jaxpr(fn)(*args))
     sorts = sorted(n for n in names if "sort" in n)
     assert not sorts, f"sort primitives in traced hot path: {sorts}"
 
